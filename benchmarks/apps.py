@@ -1,5 +1,9 @@
 """Figs 16/17: the paper's two full applications — streaming matrix
-multiply and Rabin-Karp string search — on our instrumented pipeline."""
+multiply and Rabin-Karp string search — on our instrumented pipeline.
+
+``matmul_pipeline`` / ``rabin_karp_pipeline`` build each application
+and its correctness check; ``fig16_matmul_app`` / ``fig17_rabin_karp``
+run them as benchmark rows."""
 
 from __future__ import annotations
 
@@ -11,10 +15,11 @@ from repro.core.monitor import MonitorConfig
 from repro.streams import Pipeline, Stage
 
 
-def fig16_matmul_app():
-    """Streaming dense matmul: reader -> n dot-product kernels -> reduce.
-    The reduce kernel's queue is instrumented (as in the paper)."""
-    n = 256
+def matmul_pipeline(n: int = 256):
+    """Streaming dense matmul: reader -> 4 dot-product kernels -> reduce.
+    The reduce kernel's queue is instrumented (as in the paper).
+    Returns ``(pipe, correct)``; ``correct()`` compares the reduced rows
+    with ``A @ B`` once the pipeline has drained."""
     A = np.random.default_rng(0).normal(size=(n, n)).astype(np.float32)
     B = np.random.default_rng(1).normal(size=(n, n)).astype(np.float32)
 
@@ -38,22 +43,15 @@ def fig16_matmul_app():
                      Stage("reduce", fn=reduce)],
                     capacity=32, base_period_s=2e-3,
                     monitor_cfg=MonitorConfig(window=16, min_q_samples=16))
-    t0 = time.perf_counter()
-    out = pipe.run_collect(timeout_s=120)
-    dt = time.perf_counter() - t0
-    ok = np.allclose(acc, A @ B, atol=1e-3)
-    rates = pipe.rates()
-    reduce_rate = rates["dot->reduce"]["service_rate"]
-    return ([f"fig16_matmul,{dt * 1e6:.0f},rows={len(out)}_correct={ok}"
-             f"_reduce_rate={reduce_rate:.0f}/s"],
-            f"matmul correct={ok}; instrumented reduce kernel rate "
-            f"{reduce_rate:.0f} rows/s (paper Fig 16 instruments reduce)")
+    return pipe, lambda: bool(np.allclose(acc, A @ B, atol=1e-3))
 
 
-def fig17_rabin_karp():
-    """Rabin-Karp over a 'foobar' corpus; hash kernel's out-queue
-    instrumented (paper: low-rho, hard-to-observe case)."""
-    corpus = (b"foobar" * 200_000)        # 1.2 MB of 'foobar'
+def rabin_karp_pipeline(reps: int = 200_000):
+    """Rabin-Karp over a 'foobar' * ``reps`` corpus; the hash kernel's
+    out-queue is instrumented (paper: low-rho, hard-to-observe case).
+    Returns ``(pipe, expected)``: the pipeline's output is one list of
+    verified match offsets per chunk, ``expected`` matches in all."""
+    corpus = b"foobar" * reps
     pattern = b"foobar"
     m = len(pattern)
     q = (1 << 31) - 1
@@ -91,11 +89,29 @@ def fig17_rabin_karp():
                      Stage("verify", fn=verify, replicas=2)],
                     capacity=32, base_period_s=2e-3,
                     monitor_cfg=MonitorConfig(window=16, min_q_samples=16))
+    return pipe, len(corpus) // m
+
+
+def fig16_matmul_app():
+    pipe, correct = matmul_pipeline()
+    t0 = time.perf_counter()
+    out = pipe.run_collect(timeout_s=120)
+    dt = time.perf_counter() - t0
+    ok = correct()
+    rates = pipe.rates()
+    reduce_rate = rates["dot->reduce"]["service_rate"]
+    return ([f"fig16_matmul,{dt * 1e6:.0f},rows={len(out)}_correct={ok}"
+             f"_reduce_rate={reduce_rate:.0f}/s"],
+            f"matmul correct={ok}; instrumented reduce kernel rate "
+            f"{reduce_rate:.0f} rows/s (paper Fig 16 instruments reduce)")
+
+
+def fig17_rabin_karp():
+    pipe, expect = rabin_karp_pipeline()
     t0 = time.perf_counter()
     out = pipe.run_collect(timeout_s=180)
     dt = time.perf_counter() - t0
     n_matches = sum(len(x) for x in out)
-    expect = len(corpus) // m
     rates = pipe.rates()
     vq = rates["hash->verify"]
     return ([f"fig17_rabin_karp,{dt * 1e6:.0f},matches={n_matches}"

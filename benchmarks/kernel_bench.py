@@ -125,10 +125,11 @@ def monitor_fleet_scan():
             rows.append(f"monitor_scan/rounds_{label}_q={q},{dt*1e6:.0f},"
                         f"{q*T/dt/1e6:.2f}_Mqs_per_s")
 
-    # the fused VMEM kernel (TPU contract) in interpret mode, for record
+    # the fused VMEM kernel in interpret mode on every backend, as the
+    # row's name says (like the per-tick baseline above), for record
     st0 = fleet_monitor_init(cfg, Qb)
     f = jax.jit(lambda s, t: scan_op(cfg, s, t, None, impl="pallas",
-                                     mode="full")[0].epoch)
+                                     mode="full", interpret=True)[0].epoch)
     dt = bench(f, st0, tc_b, n=1)
     report["fleet"]["pallas_interpret_q4096"] = {
         "ms": dt * 1e3, "mqs_per_s": Qb * T / dt / 1e6}

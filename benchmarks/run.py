@@ -59,6 +59,9 @@ def main(argv=None) -> None:
     if args.seed is not None:
         os.environ["REPRO_BENCH_SEED"] = str(args.seed)
 
+    from repro.core.backend import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (apps, collector_bench, control_bench,
                             kernel_bench, paper_figs, pipeline_bench,
                             roofline_table)

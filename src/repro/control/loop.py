@@ -89,7 +89,8 @@ import numpy as np
 
 from repro.control.log import ControlLog, ControlRecord
 from repro.control.policy import (ControlState, Decision, PolicySet,
-                                  control_decide, control_init)
+                                  control_decide, control_init,
+                                  resolve_impl)
 
 __all__ = ["ControlLoop"]
 
@@ -109,7 +110,7 @@ class ControlLoop(threading.Thread):
         self.service = service
         self.policies = policies
         self.actuator = actuator
-        self.impl = impl
+        self.impl = resolve_impl(impl)     # "auto": numpy on host, jit on device
         self.cfg = policies.control_config()
         self.log = log if log is not None else ControlLog()
         # one decision per fused monitor dispatch: estimates only move
@@ -512,25 +513,28 @@ class ControlLoop(threading.Thread):
         """One watchdog poll: restart the monitor thread if it died
         (started, no longer alive, never asked to stop).  Returns True
         when a restart fired; the restart is audited as
-        ``policy='watchdog'`` with ``E_MONITOR_DEAD``."""
+        ``policy='watchdog'`` with ``E_MONITOR_DEAD``.  Polls are
+        serialized on the tick lock: the run() thread and a harness
+        polling at once must not both restart one dead thread."""
         get, restart = self._mon_get, self._mon_restart
         if get is None or restart is None:
             return False
-        try:
-            m = get()
-        except Exception:
-            return False
-        if (m is None or m.ident is None or m.is_alive()
-                or m._stop_evt.is_set()):
-            return False
-        restart()
-        self.monitor_restarts += 1
-        self.log.append(ControlRecord(
-            tick=self.ticks, t=time.monotonic(), queue=-1,
-            policy="watchdog", observed_lam=0.0, observed_mu=0.0,
-            action="monitor-restart", value=self.monitor_restarts,
-            outcome="applied", error="E_MONITOR_DEAD"))
-        return True
+        with self._lock:
+            try:
+                m = get()
+            except Exception:
+                return False
+            if (m is None or m.ident is None or m.is_alive()
+                    or m._stop_evt.is_set()):
+                return False
+            restart()
+            self.monitor_restarts += 1
+            self.log.append(ControlRecord(
+                tick=self.ticks, t=time.monotonic(), queue=-1,
+                policy="watchdog", observed_lam=0.0, observed_mu=0.0,
+                action="monitor-restart", value=self.monitor_restarts,
+                outcome="applied", error="E_MONITOR_DEAD"))
+            return True
 
     def health(self) -> dict:
         """Failure-handling counters (all zero on a healthy loop)."""

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.backend import on_host
 from repro.core.controller import (BufferAutotuner, ParallelismController,
                                    StragglerDetector)
 
@@ -487,18 +488,55 @@ def _decide_step(cfg: ControlConfig, donate: bool):
     return jax.jit(step, donate_argnums=(0,) if donate else ())
 
 
-_AUTO_IMPL: list = [None]
+def resolve_impl(impl: str) -> str:
+    """The decision's execution form: ``"auto"`` is numpy on the host
+    backend (the ~150 us per-dispatch XLA CPU floor dwarfs the decision
+    itself) and jit on a device — decided by ``core.backend.on_host``,
+    the same probe that picks the kernels' interpret mode."""
+    if impl == "auto":
+        return "numpy" if on_host() else "jit"
+    if impl not in ("numpy", "jit"):
+        raise ValueError(f"bad impl {impl!r}")
+    return impl
 
 
-def _auto_impl() -> str:
-    """numpy on CPU backends (the ~150 us per-dispatch XLA CPU floor
-    dwarfs the decision itself), jit wherever an accelerator backs
-    jax — the same host-vs-device split the monitor's rounds/pallas
-    forms make."""
-    if _AUTO_IMPL[0] is None:
-        _AUTO_IMPL[0] = ("numpy" if jax.default_backend() == "cpu"
-                         else "jit")
-    return _AUTO_IMPL[0]
+# per-queue decision operands: dtype, and the fill for padded rows under
+# jit (chosen so a padded row decides nothing)
+_OPERANDS = {
+    "lam": (np.float32, 0.0), "mu": (np.float32, 0.0),
+    "ready": (bool, False), "replicas": (np.int32, 1),
+    "rep_basis": (np.int32, 1), "caps": (np.int32, 1),
+    "cv2": (np.float32, 1.0), "occupancy": (np.float32, 0.0),
+    "saturated": (bool, False), "scalable": (bool, False),
+    "stale": (bool, False), "faulty": (bool, False),
+    "leg_rep": (bool, False), "leg_buf": (bool, False),
+    "leg_adm": (bool, False), "headroom": (np.float32, 1.0),
+    "max_reps": (np.int32, 1),
+    # padded rows must never arm via pressure: hi=2 is unreachable
+    "occ_hi": (np.float32, 2.0), "occ_lo": (np.float32, 0.0),
+    "pressure": (np.float32, 0.0),
+    # NaN pad = no SLO on padded rows (the leg's own neutral value)
+    "slo_target": (np.float32, np.nan), "over_frac": (np.float32, np.nan),
+}
+
+
+def _jit_operands(cfg: "ControlConfig", state: "ControlState", q: int,
+                  fleet_med: float, ops: dict):
+    """Device operands for ``_decide_step``: every (Q,) operand (scalars
+    broadcast) padded to a ``cfg.block_q`` multiple, and the state with
+    it.  Returns ``(state, operands)``."""
+    rpad = -(-q // cfg.block_q) * cfg.block_q - q
+    out = {"fleet_med": jnp.float32(fleet_med)}
+    for name, v in ops.items():
+        dt, fill = _OPERANDS[name]
+        a = jnp.broadcast_to(jnp.asarray(v, dt), (q,))
+        out[name] = (jnp.pad(a, (0, rpad), constant_values=fill)
+                     if rpad else a)
+    state = ControlState(*(jnp.asarray(leaf) for leaf in state))
+    if rpad:
+        state = jax.tree_util.tree_map(
+            lambda a: jnp.pad(a, (0, rpad)), state)
+    return state, out
 
 
 def control_decide(cfg: ControlConfig, state: ControlState, *,
@@ -591,80 +629,33 @@ def control_decide(cfg: ControlConfig, state: ControlState, *,
     ready_np = np.asarray(ready, bool)
     fleet_med = (float(np.median(mu_np[ready_np]))
                  if ready_np.any() else 0.0)
-    if impl == "auto":
-        impl = _auto_impl()
+    impl = resolve_impl(impl)
+    ops = dict(
+        lam=lam, mu=mu, ready=ready, replicas=replicas,
+        rep_basis=rep_basis, caps=caps, cv2=cv2, occupancy=occupancy,
+        saturated=saturated, scalable=scalable, stale=stale,
+        faulty=faulty, leg_rep=leg_rep, leg_buf=leg_buf, leg_adm=leg_adm,
+        headroom=headroom, max_reps=max_replicas, occ_hi=occ_hi,
+        occ_lo=occ_lo, pressure=pressure, slo_target=slo_target,
+        over_frac=over_frac)
 
     if impl == "numpy":
-        def npa(a, dt):
-            a = np.asarray(a, dt)
+        def npa(name, v):
+            a = np.asarray(v, _OPERANDS[name][0])
             return np.broadcast_to(a, (q,)) if a.ndim == 0 else a
 
         st = ControlState(*(np.asarray(leaf) for leaf in state))
+        ops = {name: npa(name, v) for name, v in ops.items()}
         # masked-out lanes (mu <= 0 etc.) compute garbage by design and
         # are discarded by the final where — same as under XLA, minus
         # the numpy warnings
         with np.errstate(divide="ignore", invalid="ignore"):
-            return _step_math(
-                np, cfg, st, lam=lam, mu=npa(mu, np.float32),
-                ready=npa(ready, bool), replicas=npa(replicas, np.int32),
-                rep_basis=npa(rep_basis, np.int32),
-                caps=npa(caps, np.int32), cv2=npa(cv2, np.float32),
-                occupancy=npa(occupancy, np.float32),
-                saturated=npa(saturated, bool),
-                scalable=npa(scalable, bool),
-                fleet_med=np.float32(fleet_med),
-                stale=npa(stale, bool), faulty=npa(faulty, bool),
-                leg_rep=npa(leg_rep, bool), leg_buf=npa(leg_buf, bool),
-                leg_adm=npa(leg_adm, bool),
-                headroom=npa(headroom, np.float32),
-                max_reps=npa(max_replicas, np.int32),
-                occ_hi=npa(occ_hi, np.float32),
-                occ_lo=npa(occ_lo, np.float32),
-                pressure=npa(pressure, np.float32),
-                slo_target=npa(slo_target, np.float32),
-                over_frac=npa(over_frac, np.float32))
-    if impl != "jit":
-        raise ValueError(f"bad impl {impl!r}")
+            return _step_math(np, cfg, st, fleet_med=np.float32(fleet_med),
+                              **ops)
 
-    b = cfg.block_q
-    rpad = -(-q // b) * b - q
-
-    def pad(a, fill=0):
-        a = jnp.asarray(a)
-        a = jnp.broadcast_to(a, (q,)) if a.ndim == 0 else a
-        return jnp.pad(a, (0, rpad), constant_values=fill) if rpad else a
-
-    operands = dict(
-        lam=pad(jnp.asarray(lam)), mu=pad(jnp.asarray(mu, jnp.float32)),
-        ready=pad(jnp.asarray(ready, bool), False),
-        replicas=pad(jnp.asarray(replicas, jnp.int32), 1),
-        rep_basis=pad(jnp.asarray(rep_basis, jnp.int32), 1),
-        caps=pad(jnp.asarray(caps, jnp.int32), 1),
-        cv2=pad(jnp.asarray(cv2, jnp.float32), 1.0),
-        occupancy=pad(jnp.asarray(occupancy, jnp.float32)),
-        saturated=pad(jnp.asarray(saturated, bool), False),
-        scalable=pad(jnp.asarray(scalable, bool), False),
-        fleet_med=jnp.float32(fleet_med),
-        stale=pad(jnp.asarray(stale, bool), False),
-        faulty=pad(jnp.asarray(faulty, bool), False),
-        leg_rep=pad(jnp.asarray(leg_rep, bool), False),
-        leg_buf=pad(jnp.asarray(leg_buf, bool), False),
-        leg_adm=pad(jnp.asarray(leg_adm, bool), False),
-        headroom=pad(jnp.asarray(headroom, jnp.float32), 1.0),
-        max_reps=pad(jnp.asarray(max_replicas, jnp.int32), 1),
-        # padded rows must never arm via pressure: hi=2 is unreachable
-        occ_hi=pad(jnp.asarray(occ_hi, jnp.float32), 2.0),
-        occ_lo=pad(jnp.asarray(occ_lo, jnp.float32), 0.0),
-        pressure=pad(jnp.asarray(pressure, jnp.float32), 0.0),
-        # NaN pad = no SLO on padded rows (the leg's own neutral value)
-        slo_target=pad(jnp.asarray(slo_target, jnp.float32), np.nan),
-        over_frac=pad(jnp.asarray(over_frac, jnp.float32), np.nan))
-    state = ControlState(*(jnp.asarray(leaf) for leaf in state))
-    if rpad:
-        state = jax.tree_util.tree_map(
-            lambda a: jnp.pad(a, (0, rpad)), state)
+    state, operands = _jit_operands(cfg, state, q, fleet_med, ops)
     state, dec = _decide_step(cfg, donate)(state, **operands)
-    if rpad:
+    if state.cooldown.shape[0] != q:       # drop the padded rows
         state = jax.tree_util.tree_map(lambda a: a[:q], state)
         dec = jax.tree_util.tree_map(lambda a: a[:q], dec)
     return state, dec

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import filters
+from repro.core.backend import resolve_interpret
 from repro.core.stats import (Welford, welford_init, welford_update,
                               welford_stderr)
 
@@ -372,7 +373,7 @@ def _fleet_dispatch(cfg: MonitorConfig, impl: str, mode: str,
 def run_monitor_fleet(cfg: MonitorConfig, tc_seq, blocked_seq=None, *,
                       state: FleetMonitorState | None = None,
                       chunk_t: int = 256, impl: str = "rounds",
-                      mode: str = "full", interpret: bool = True,
+                      mode: str = "full", interpret: bool | None = None,
                       block_q: int = 256, dtype=jnp.float32,
                       donate: bool = False, pad_q: bool = True
                       ) -> tuple[FleetMonitorState, MonitorOutput | None]:
@@ -385,9 +386,11 @@ def run_monitor_fleet(cfg: MonitorConfig, tc_seq, blocked_seq=None, *,
 
     ``impl`` selects the execution path (see ``kernels.monitor.ops``):
     ``"rounds"`` (segmented time-batched XLA form — the CPU fast path),
-    ``"pallas"`` (the fused VMEM-resident kernel; the TPU contract, run
-    in interpret mode elsewhere) or ``"scan"`` (pure-jnp sequential
-    oracle).  ``mode="full"`` returns a ``MonitorOutput`` whose (Q, T)
+    ``"pallas"`` (the fused VMEM-resident kernel: compiled on a TPU,
+    run by the Pallas interpreter on the CPU backend unless
+    ``interpret`` says otherwise — see ``core.backend``) or ``"scan"``
+    (pure-jnp sequential oracle).  ``mode="full"`` returns a
+    ``MonitorOutput`` whose (Q, T)
     leaves are step-for-step identical to ``jax.vmap(run_monitor)``;
     ``mode="state"`` skips per-step outputs (converged estimates and
     epochs live in the state) and returns ``(state, None)`` — the
@@ -420,7 +423,8 @@ def run_monitor_fleet(cfg: MonitorConfig, tc_seq, blocked_seq=None, *,
             lambda a: jnp.pad(a, ((0, rpad),) + ((0, 0),) * (a.ndim - 1)),
             state)
 
-    step = _fleet_dispatch(cfg, impl, mode, interpret, block_q, donate)
+    step = _fleet_dispatch(cfg, impl, mode, resolve_interpret(interpret),
+                           block_q, donate)
     outs = []
     for t0 in range(0, T, chunk_t):
         tc_c = tc_seq[:, t0:t0 + chunk_t]

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.backend import resolve_interpret
+
 __all__ = ["flash_attention_pallas"]
 
 _NEG = -1e30
@@ -34,14 +36,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq, bk, t_len, scale,
 
     def body(j, carry):
         acc, m, l = carry
-        # all-slice indices: plain-int 0s break the interpret-mode
-        # discharge rule on static trip counts (jax 0.4.37)
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(j * bk, bk),
-                            pl.dslice(0, 1), pl.dslice(None)))[
-                                0, :, 0, :].astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(j * bk, bk),
-                            pl.dslice(0, 1), pl.dslice(None)))[
-                                0, :, 0, :].astype(jnp.float32)
+        kv = (0, pl.ds(j * bk, bk), 0, slice(None))
+        k = k_ref[kv].astype(jnp.float32)
+        v = v_ref[kv].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (BQ,BK)
         if causal:
             q_idx = qi * bq + jax.lax.broadcasted_iota(
@@ -71,7 +68,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq, bk, t_len, scale,
                                              "block_k", "interpret"))
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """q: (B,S,H,hd) k/v: (B,T,K,hd) GQA -> (B,S,H,hd) float32."""
     B, S, H, hd = q.shape
     T, K = k.shape[1], k.shape[2]
@@ -97,6 +94,6 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         out_specs=pl.BlockSpec((1, BQ, 1, hd),
                                lambda b, h, i: (b, i, h, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, H, hd), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return out

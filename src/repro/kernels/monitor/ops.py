@@ -39,12 +39,13 @@ __all__ = ["fleet_monitor_q", "fleet_monitor_step", "fleet_monitor_scan",
 # ---------------------------------------------------------------------------
 
 def _pack_state(state: FleetMonitorState):
+    """Per-queue scalars as the kernel's lane-major (8, Q) row blocks."""
     z_f = jnp.zeros_like(state.count)
     z_i = jnp.zeros_like(state.s_fill)
     fstate = jnp.stack([state.count, state.mean, state.m2,
-                        state.last_qbar, z_f, z_f, z_f, z_f], axis=1)
+                        state.last_qbar, z_f, z_f, z_f, z_f], axis=0)
     istate = jnp.stack([state.s_fill, state.epoch, z_i, z_i, z_i, z_i,
-                        z_i, z_i], axis=1)
+                        z_i, z_i], axis=0)
     return fstate, istate
 
 
@@ -85,7 +86,8 @@ def _compact(tc, blocked):
 
 def _fleet_monitor_scan_impl(cfg: MonitorConfig, state: FleetMonitorState,
                              tc, blocked=None, *, impl: str = "rounds",
-                             mode: str = "full", interpret: bool = True,
+                             mode: str = "full",
+                             interpret: bool | None = None,
                              block_q: int = 256, sub_t: int = 32):
     """One fused dispatch over a (Q, T) tile.
 
@@ -104,17 +106,19 @@ def _fleet_monitor_scan_impl(cfg: MonitorConfig, state: FleetMonitorState,
     full = mode == "full"
     q_c = None
     if impl == "pallas":
+        # the kernel is lane-major (queues on lanes): transpose (Q, k)
+        # leaves in and out, and pad the lane axis to a block multiple
         BQ = block_q
         Qp = -(-Q // BQ) * BQ
-        pad = Qp - Q
-        pad2 = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))  # noqa: E731
+        lanes = lambda a: jnp.pad(a, ((0, 0), (0, Qp - Q)))  # noqa: E731
         fstate, istate = _pack_state(state)
         outs = monitor_fleet_pallas(
-            cfg, pad2(comp), pad2(m), pad2(state.win), pad2(fstate),
-            pad2(istate), pad2(state.qhist), pad2(state.shist),
-            pad2(state.rhist), block_q=BQ, interpret=interpret)
+            cfg, lanes(comp.T), lanes(m[None, :]), lanes(state.win.T),
+            lanes(fstate), lanes(istate), lanes(state.qhist.T),
+            lanes(state.shist.T), lanes(state.rhist.T), block_q=BQ,
+            interpret=interpret)
         (q_c, qbar_c, sig_c, conv_c, est_c, ep_c,
-         fout, iout, qhist, shist, rhist) = [o[:Q] for o in outs]
+         fout, iout, qhist, shist, rhist) = [o[:, :Q].T for o in outs]
         carry = (iout[:, 0], fout[:, 0], fout[:, 1], fout[:, 2],
                  qhist, shist, rhist, iout[:, 1], fout[:, 3])
     elif impl == "scan":
@@ -196,7 +200,7 @@ fleet_monitor_scan = functools.partial(
 # ---------------------------------------------------------------------------
 
 def fleet_monitor_q(windows, *, use_pallas: bool = True,
-                    interpret: bool = True, block_q: int = 256):
+                    interpret: bool | None = None, block_q: int = 256):
     """(Q, w) windows -> (Q,) Eq.3 quantile estimates."""
     if use_pallas:
         q, _, _ = batched_monitor_pallas(windows, interpret=interpret,
@@ -226,7 +230,8 @@ def fleet_step_init(cfg: MonitorConfig, n_queues: int,
 
 
 def fleet_monitor_step(windows, state, *, cfg: Optional[MonitorConfig] = None,
-                       use_pallas: bool = True, interpret: bool = True):
+                       use_pallas: bool = True,
+                       interpret: bool | None = None):
     """One fleet monitoring tick: (Q, w) windows + per-queue stats state
     -> ``(q, new_state, sigma_qbar)``.
 

@@ -37,18 +37,20 @@ __all__ = ["batched_monitor_ref", "monitor_fleet_ref",
            "slide_max_valid"]
 
 
-def fleet_sigma(count, m2, qhist, *, window_std: bool, cw: int):
+def fleet_sigma(count, m2, qhist, *, window_std: bool, cw: int,
+                axis: int = 1):
     """The fleet paths' sigma(q-bar), one definition for all of them.
 
     window_std: masked std of the last ``cw`` q-bar folds, gated on
     ``count >= cw`` with the not-ready ``_BIG`` sentinel otherwise.
     Else the Welford stderr sqrt(m2 / count^2) with empty-stats guard
-    (matches ``stats.welford_stderr``).
+    (matches ``stats.welford_stderr``).  ``axis`` is the history axis of
+    ``qhist`` (see ``fleet_step``).
     """
     if window_std:
-        muq = jnp.mean(qhist, axis=1)
-        dq = qhist - muq[:, None]
-        sig = jnp.sqrt(jnp.mean(dq * dq, axis=1))
+        muq = jnp.mean(qhist, axis=axis, keepdims=True)
+        dq = qhist - muq
+        sig = jnp.sqrt(jnp.mean(dq * dq, axis=axis, keepdims=axis == 0))
         return jnp.where(count >= cw, sig, jnp.asarray(_BIG, sig.dtype))
     safe = jnp.where(count > 0, count, 1.0)
     var = jnp.where(count > 0, m2 / safe, 0.0)
@@ -100,63 +102,69 @@ def fleet_static_params(cfg: MonitorConfig) -> types.SimpleNamespace:
     )
 
 
-def _ladder(x, n, combine):
-    """Valid-mode sliding reduce of width n over the last axis, built as
-    a static shifted-slice doubling ladder (no pads, no gathers — fuses
+def _sl(x, start, stop, axis):
+    return jax.lax.slice_in_dim(x, start, stop, axis=axis)
+
+
+def _ladder(x, n, combine, axis):
+    """Valid-mode sliding reduce of width n along ``axis``, built as a
+    static shifted-slice doubling ladder (no pads, no gathers — fuses
     well under XLA and lowers on TPU)."""
-    L = x.shape[-1]
+    L = x.shape[axis]
     n_out = L - n + 1
     pows = {1: x}
     k = 1
     while k * 2 <= n:
         s = pows[k]
-        pows[k * 2] = combine(s[..., :s.shape[-1] - k], s[..., k:])
+        m = s.shape[axis]
+        pows[k * 2] = combine(_sl(s, 0, m - k, axis), _sl(s, k, m, axis))
         k *= 2
     acc = None
     off = 0
     for k in sorted(pows, reverse=True):
         if n & k:
-            part = pows[k][..., off:off + n_out]
+            part = _sl(pows[k], off, off + n_out, axis)
             acc = part if acc is None else combine(acc, part)
             off += k
     return acc
 
 
-def slide_sum_valid(x, n):
-    return _ladder(x, n, jnp.add)
+def slide_sum_valid(x, n, axis=-1):
+    return _ladder(x, n, jnp.add, axis % x.ndim)
 
 
-def slide_max_valid(x, n):
-    return _ladder(x, n, jnp.maximum)
+def slide_max_valid(x, n, axis=-1):
+    return _ladder(x, n, jnp.maximum, axis % x.ndim)
 
 
 # ---------------------------------------------------------------------------
 # Stage A: time-batched window estimates.
 # ---------------------------------------------------------------------------
 
-def fleet_window_stage(P, win, comp):
+def fleet_window_stage(P, win, comp, axis: int = 1):
     """Time-batched Eq. 2+3 over a compacted tile.
 
     win: (B, w) carried window (newest last); comp: (B, T) compacted
     valid samples.  Returns q: (B, T) — the Eq. 3 quantile after each
     compacted sample (garbage until the window is full; callers gate on
-    readiness).
+    readiness).  ``axis`` is the time axis: 1 as above, 0 for the
+    transposed (w, B) / (T, B) layout of the Pallas kernel.
     """
     W, r, n = P.window, P.gauss_radius, P.window - 2 * P.gauss_radius
-    T = comp.shape[1]
-    ext = jnp.concatenate([win, comp], axis=1)           # (B, W+T)
+    T = comp.shape[axis]
+    ext = jnp.concatenate([win, comp], axis=axis)        # (B, W+T)
     L = W + T - 2 * r
-    conv = ext[:, :L] * P.gauss_taps[0]
+    conv = _sl(ext, 0, L, axis) * P.gauss_taps[0]
     for i in range(1, 2 * r + 1):
-        conv = conv + ext[:, i:i + L] * P.gauss_taps[i]  # (B, L)
+        conv = conv + _sl(ext, i, i + L, axis) * P.gauss_taps[i]  # (B, L)
     # center first: the windowed sums then cancel at ~machine eps in f32
-    c = jnp.mean(conv, axis=1, keepdims=True)
+    c = jnp.mean(conv, axis=axis, keepdims=True)
     d = conv - c
-    s1 = slide_sum_valid(d, n)                           # (B, T+1)
-    s2 = slide_sum_valid(d * d, n)
+    s1 = slide_sum_valid(d, n, axis)                     # (B, T+1)
+    s2 = slide_sum_valid(d * d, n, axis)
     # step t's window ends at ext col W+t -> sum windows start at t+1
-    mu = s1[:, 1:] / n
-    var = s2[:, 1:] / n - mu * mu
+    mu = _sl(s1, 1, T + 1, axis) / n
+    var = _sl(s2, 1, T + 1, axis) / n - mu * mu
     sd = jnp.sqrt(jnp.maximum(var, 0.0))
     return mu + c + P.z * sd
 
@@ -172,22 +180,35 @@ def carry_of_state(state) -> tuple:
             state.epoch, state.last_qbar)
 
 
-def fleet_step(P, carry, q_t, t, m):
+def fleet_step(P, carry, q_t, t, m, axis: int = 1):
     """One Stage-B step: fold one compacted sample's q for every queue.
 
-    All carries are (B,) vectors or chronological (B, k) histories;
-    every update is a masked vector op with no data-dependent control
-    flow.  Returns (new_carry, outputs) with outputs a 6-tuple of (B,)
-    columns in ``MonitorOutput`` order.
+    All carries are per-queue vectors or chronological histories; every
+    update is a masked vector op with no data-dependent control flow.
+    ``axis`` is the history axis: 1 for (B, k) histories with (B,)
+    per-queue vectors (the scan oracle), 0 for (k, B) histories with
+    (1, B) per-queue rows (the Pallas kernel: queues on lanes, history
+    on sublanes).  Returns (new_carry, outputs) with outputs a 6-tuple of
+    per-queue vectors in ``MonitorOutput`` order.
     """
     (s_fill, count, mean, m2, qhist, shist, rhist, epoch, last_qbar) = carry
     W, CW = P.window, P.conv_window
     SL = CW + 2
 
+    def col(v):       # a per-queue vector, broadcastable over a history
+        return v[:, None] if axis == 1 else v
+
+    def entry(h, i):  # history entry i as a per-queue vector
+        return h[:, i] if axis == 1 else h[i:i + 1]
+
+    def push(h, v):   # chronological shift-push of a per-queue vector
+        return jnp.concatenate([_sl(h, 1, h.shape[axis], axis), col(v)],
+                               axis=axis)
+
     valid = t < m
     s_fill = jnp.minimum(s_fill + valid.astype(jnp.int32), W)
     ready = jnp.logical_and(valid, s_fill >= W)
-    rc = ready[:, None]
+    rc = col(ready)
 
     # Welford fold (identical op order to stats.welford_update)
     cnt1 = count + 1.0
@@ -200,22 +221,20 @@ def fleet_step(P, carry, q_t, t, m):
     qbar = mean
 
     # chronological shift-push (fills are functions of count, see state)
-    qhist = jnp.where(rc, jnp.concatenate(
-        [qhist[:, 1:], qbar[:, None]], axis=1), qhist)
-    sig = fleet_sigma(count, m2, qhist, window_std=P.window_std, cw=CW)
+    qhist = jnp.where(rc, push(qhist, qbar), qhist)
+    sig = fleet_sigma(count, m2, qhist, window_std=P.window_std, cw=CW,
+                      axis=axis)
 
     # LoG response over the chronological (t-2, t-1, t) sigma stencil; a
     # response enters the history only once all three taps are post-reset
     l0, l1, l2 = P.log_taps
-    resp_new = l0 * shist[:, 0] + l1 * shist[:, 1] + l2 * sig
-    push = jnp.logical_and(ready, count >= 3)
-    rhist = jnp.where(push[:, None], jnp.concatenate(
-        [rhist[:, 1:], resp_new[:, None]], axis=1), rhist)
-    shist = jnp.where(rc, jnp.concatenate(
-        [shist[:, 1:], sig[:, None]], axis=1), shist)
+    resp_new = l0 * entry(shist, 0) + l1 * entry(shist, 1) + l2 * sig
+    rpush = jnp.logical_and(ready, count >= 3)
+    rhist = jnp.where(col(rpush), push(rhist, resp_new), rhist)
+    shist = jnp.where(rc, push(shist, sig), shist)
 
     # convergence test (Eq. 4): count >= SL <=> CW responses post-reset
-    resp = jnp.max(jnp.abs(rhist), axis=1)
+    resp = jnp.max(jnp.abs(rhist), axis=axis, keepdims=axis == 0)
     trace_ready = count >= max(SL, P.min_q)
     tol = jnp.asarray(P.conv_tol, qbar.dtype)
     if P.rel_tol:

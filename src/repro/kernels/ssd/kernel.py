@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.backend import resolve_interpret
+
 __all__ = ["ssd_chunk_kernel", "ssd_chunk_pallas"]
 
 
@@ -50,7 +52,7 @@ def ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_chunk_pallas(x, dt, A, Bm, Cm, *, interpret: bool = True):
+def ssd_chunk_pallas(x, dt, A, Bm, Cm, *, interpret: bool | None = None):
     """Batched intra-chunk SSD.
 
     x: (B,c,Q,H,P) dt: (B,c,Q,H) A: (H,) Bm/Cm: (B,c,Q,N)
@@ -82,7 +84,7 @@ def ssd_chunk_pallas(x, dt, A, Bm, Cm, *, interpret: bool = True):
             jax.ShapeDtypeStruct((B, c, H, P, N), f32),
             jax.ShapeDtypeStruct((B, c, H), f32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xt.astype(f32), dt.astype(f32), A.astype(f32),
       Bm.astype(f32), Cm.astype(f32))
     return jnp.moveaxis(y, 2, 3), state, decay

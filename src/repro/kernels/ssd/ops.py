@@ -17,7 +17,7 @@ __all__ = ["ssd_chunked_pallas", "ssd_chunk_ref"]
 
 
 def ssd_chunked_pallas(x, dt, A, Bm, Cm, chunk: int, *, h0=None,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """x: (B,S,H,P) dt: (B,S,H) A: (H,) Bm/Cm: (B,S,N) -> (y, hT)."""
     B, S, H, P = x.shape
     N = Bm.shape[-1]

@@ -353,19 +353,27 @@ def test_per_class_deadline_drops_under_sustained_load():
 def test_engine_monitor_watchdog_restarts_dead_thread():
     from repro.serve import ServeConfig
     from repro.streams import CounterArena
-    plan = FaultPlan([FaultEvent(0.0, "monitor_death")]).arm()
+    plan = FaultPlan([FaultEvent(0.5, "monitor_death")])
     eng = _work_engine(
         ServeConfig(batch_size=1, queue_capacity=16, bulkheads=(1, 1)),
         work_s=0.0, arena=CounterArena(8), control=True, fault_plan=plan)
+    # compile before the clock starts: the thread's own warm-up is then
+    # a cache hit, and a loaded host cannot stretch it past the join
+    eng.fleet.warmup()
+    plan.arm()
     eng.start()
     try:
         dead = eng.monitor_thread
         dead.join(timeout=10)              # injected silent death
         assert not dead.is_alive()
-        assert eng.control.check_monitor()
+        # the engine's running loop polls too: whichever poll comes
+        # first restarts the thread, and the other finds it alive
+        eng.control.check_monitor()
         assert eng.monitor_thread is not dead
         assert eng.monitor_thread.is_alive()
         assert eng.control.health()["monitor_restarts"] == 1
+        assert [r.error for r in eng.control.log.records()
+                if r.policy == "watchdog"] == ["E_MONITOR_DEAD"]
     finally:
         eng.stop()
 

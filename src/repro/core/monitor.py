@@ -402,52 +402,61 @@ def run_monitor_fleet(cfg: MonitorConfig, tc_seq, blocked_seq=None, *,
     executable.  ``donate=True`` donates the state into the dispatch (the
     caller must not reuse the passed-in ``state``) so the (Q,)-leaf fleet
     state updates in place — the monitoring-service hot path.
+
+    Under a ``jax.profiler`` trace the host stages show as spans:
+    ``repro.monitor.stage`` (host -> device), ``.pad``, ``.dispatch``
+    and ``.unpad`` (see ``kernels/monitor/README.md``, "Tracing").
     """
-    tc_seq = jnp.asarray(tc_seq, dtype)
-    if tc_seq.ndim != 2:
-        raise ValueError(f"tc_seq must be (Q, T), got {tc_seq.shape}")
-    Q, T = tc_seq.shape
-    if blocked_seq is not None:
-        blocked_seq = jnp.asarray(blocked_seq, jnp.bool_)
-    if state is None:
-        state = fleet_monitor_init(cfg, Q, dtype)
+    annotate = jax.profiler.TraceAnnotation
+    with annotate("repro.monitor.stage"):    # host -> device transfer
+        tc_seq = jnp.asarray(tc_seq, dtype)
+        if tc_seq.ndim != 2:
+            raise ValueError(f"tc_seq must be (Q, T), got {tc_seq.shape}")
+        Q, T = tc_seq.shape
+        if blocked_seq is not None:
+            blocked_seq = jnp.asarray(blocked_seq, jnp.bool_)
+        if state is None:
+            state = fleet_monitor_init(cfg, Q, dtype)
 
     rpad = (-(-Q // block_q) * block_q - Q) if pad_q else 0
     if rpad:                      # padded rows are permanently blocked
-        if blocked_seq is None:
-            blocked_seq = jnp.zeros((Q, T), jnp.bool_)
-        tc_seq = jnp.pad(tc_seq, ((0, rpad), (0, 0)))
-        blocked_seq = jnp.pad(blocked_seq, ((0, rpad), (0, 0)),
-                              constant_values=True)
-        state = jax.tree_util.tree_map(
-            lambda a: jnp.pad(a, ((0, rpad),) + ((0, 0),) * (a.ndim - 1)),
-            state)
+        with annotate("repro.monitor.pad"):
+            if blocked_seq is None:
+                blocked_seq = jnp.zeros((Q, T), jnp.bool_)
+            tc_seq = jnp.pad(tc_seq, ((0, rpad), (0, 0)))
+            blocked_seq = jnp.pad(blocked_seq, ((0, rpad), (0, 0)),
+                                  constant_values=True)
+            state = jax.tree_util.tree_map(
+                lambda a: jnp.pad(a, ((0, rpad),)
+                                  + ((0, 0),) * (a.ndim - 1)), state)
 
-    step = _fleet_dispatch(cfg, impl, mode, resolve_interpret(interpret),
-                           block_q, donate)
-    outs = []
-    for t0 in range(0, T, chunk_t):
-        tc_c = tc_seq[:, t0:t0 + chunk_t]
-        blk_c = (None if blocked_seq is None
-                 else blocked_seq[:, t0:t0 + chunk_t])
-        pad = chunk_t - tc_c.shape[1]
-        if pad:                            # pad the tail chunk as blocked
-            if blk_c is None:
-                blk_c = jnp.zeros(tc_c.shape, jnp.bool_)
-            tc_c = jnp.pad(tc_c, ((0, 0), (0, pad)))
-            blk_c = jnp.pad(blk_c, ((0, 0), (0, pad)),
-                            constant_values=True)
-        state, out = step(state, tc_c, blk_c)
-        if pad:                            # padded steps are not real
-            state = state._replace(n_total=state.n_total - pad,
-                                   n_blocked=state.n_blocked - pad)
-        outs.append(out)
-    if rpad:
-        state = jax.tree_util.tree_map(lambda a: a[:Q], state)
-    if mode != "full":
-        return state, None
-    merged = MonitorOutput(*(jnp.concatenate(parts, axis=1)[:Q, :T]
-                             for parts in zip(*outs)))
+    with annotate("repro.monitor.dispatch"):
+        step = _fleet_dispatch(cfg, impl, mode,
+                               resolve_interpret(interpret), block_q, donate)
+        outs = []
+        for t0 in range(0, T, chunk_t):
+            tc_c = tc_seq[:, t0:t0 + chunk_t]
+            blk_c = (None if blocked_seq is None
+                     else blocked_seq[:, t0:t0 + chunk_t])
+            pad = chunk_t - tc_c.shape[1]
+            if pad:                        # pad the tail chunk as blocked
+                if blk_c is None:
+                    blk_c = jnp.zeros(tc_c.shape, jnp.bool_)
+                tc_c = jnp.pad(tc_c, ((0, 0), (0, pad)))
+                blk_c = jnp.pad(blk_c, ((0, 0), (0, pad)),
+                                constant_values=True)
+            state, out = step(state, tc_c, blk_c)
+            if pad:                        # padded steps are not real
+                state = state._replace(n_total=state.n_total - pad,
+                                       n_blocked=state.n_blocked - pad)
+            outs.append(out)
+    with annotate("repro.monitor.unpad"):
+        if rpad:
+            state = jax.tree_util.tree_map(lambda a: a[:Q], state)
+        if mode != "full":
+            return state, None
+        merged = MonitorOutput(*(jnp.concatenate(parts, axis=1)[:Q, :T]
+                                 for parts in zip(*outs)))
     return state, merged
 
 

@@ -100,7 +100,8 @@ def _fleet_monitor_scan_impl(cfg: MonitorConfig, state: FleetMonitorState,
     tc = jnp.asarray(tc, jnp.float32)
     Q, T = tc.shape
     W = cfg.window
-    comp, m, cnt = _compact(tc, blocked)
+    with jax.named_scope("monitor.compact"):
+        comp, m, cnt = _compact(tc, blocked)
 
     # --- fused scan over the compacted tile -----------------------------
     full = mode == "full"
@@ -111,16 +112,19 @@ def _fleet_monitor_scan_impl(cfg: MonitorConfig, state: FleetMonitorState,
         BQ = block_q
         Qp = -(-Q // BQ) * BQ
         lanes = lambda a: jnp.pad(a, ((0, 0), (0, Qp - Q)))  # noqa: E731
-        fstate, istate = _pack_state(state)
-        outs = monitor_fleet_pallas(
-            cfg, lanes(comp.T), lanes(m[None, :]), lanes(state.win.T),
-            lanes(fstate), lanes(istate), lanes(state.qhist.T),
-            lanes(state.shist.T), lanes(state.rhist.T), block_q=BQ,
-            interpret=interpret)
-        (q_c, qbar_c, sig_c, conv_c, est_c, ep_c,
-         fout, iout, qhist, shist, rhist) = [o[:, :Q].T for o in outs]
-        carry = (iout[:, 0], fout[:, 0], fout[:, 1], fout[:, 2],
-                 qhist, shist, rhist, iout[:, 1], fout[:, 3])
+        with jax.named_scope("monitor.layout"):
+            fstate, istate = _pack_state(state)
+            ins = [lanes(a) for a in (comp.T, m[None, :], state.win.T,
+                                      fstate, istate, state.qhist.T,
+                                      state.shist.T, state.rhist.T)]
+        with jax.named_scope("monitor.pallas"):
+            outs = monitor_fleet_pallas(cfg, *ins, block_q=BQ,
+                                        interpret=interpret)
+        with jax.named_scope("monitor.layout"):
+            (q_c, qbar_c, sig_c, conv_c, est_c, ep_c,
+             fout, iout, qhist, shist, rhist) = [o[:, :Q].T for o in outs]
+            carry = (iout[:, 0], fout[:, 0], fout[:, 1], fout[:, 2],
+                     qhist, shist, rhist, iout[:, 1], fout[:, 3])
     elif impl == "scan":
         carry, (q_c, qbar_c, sig_c, conv_c, est_c, ep_c) = \
             monitor_fleet_ref(cfg, state, comp, m)
@@ -133,17 +137,18 @@ def _fleet_monitor_scan_impl(cfg: MonitorConfig, state: FleetMonitorState,
         raise ValueError(f"unknown impl {impl!r}")
 
     # --- window carry: last W valid samples per queue -------------------
-    if impl == "rounds":   # rounds maintains the window itself
-        carry, win = carry[:9], carry[9]
-    else:
-        ext = jnp.concatenate([state.win, comp], axis=1)    # (Q, W+T)
-        idx = m[:, None] + jnp.arange(W)[None, :]
-        win = jnp.take_along_axis(ext, idx, axis=1)
+    with jax.named_scope("monitor.carry"):
+        if impl == "rounds":   # rounds maintains the window itself
+            carry, win = carry[:9], carry[9]
+        else:
+            ext = jnp.concatenate([state.win, comp], axis=1)  # (Q, W+T)
+            idx = m[:, None] + jnp.arange(W)[None, :]
+            win = jnp.take_along_axis(ext, idx, axis=1)
 
-    n_total = state.n_total + T
-    n_blocked = state.n_blocked + (
-        jnp.zeros((Q,), jnp.int32) if blocked is None
-        else jnp.sum(blocked, axis=1, dtype=jnp.int32))
+        n_total = state.n_total + T
+        n_blocked = state.n_blocked + (
+            jnp.zeros((Q,), jnp.int32) if blocked is None
+            else jnp.sum(blocked, axis=1, dtype=jnp.int32))
     new_state = _carry_to_state(carry, win, n_total, n_blocked)
 
     if not full:

@@ -24,10 +24,14 @@ the sequential form keeps, so all implementations share
 
 Everything is shifted-slice ladders and O(Q) gathers — no scatters
 beyond compaction, no cumsum primitives, no per-sample control flow.
+The precompute, each detection and each carry evaluation run under
+``jax.named_scope`` (``monitor.window`` / ``monitor.detect`` /
+``monitor.carry``), so a profiler trace names the phase of every op.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.monitor import _BIG, MonitorConfig
@@ -75,16 +79,18 @@ def monitor_fleet_rounds(cfg: MonitorConfig, state, comp, m, *,
     epoch, last = state.epoch, state.last_qbar
 
     # ---- dispatch-scope precompute (tiling-invariant) ----
-    q = fleet_window_stage(P, state.win, comp)               # (Q, T)
-    mc_g = m[:, None]
-    F0 = jnp.maximum(W - 1 - state.s_fill, 0)[:, None]       # first fold
-    tt_g = jnp.arange(T)[None, :]
-    ready_g = (tt_g < mc_g) & (tt_g >= F0)
-    nready = jnp.maximum(jnp.sum(ready_g, 1, keepdims=True), 1)
-    cq = jnp.sum(jnp.where(ready_g, q, 0.0), 1, keepdims=True) / nready
-    dq = jnp.where(ready_g, q - cq, 0.0)
-    ps1 = _prefix(dq)                                        # (Q, T+1)
-    ps2 = _prefix(dq * dq)
+    with jax.named_scope("monitor.window"):
+        q = fleet_window_stage(P, state.win, comp)           # (Q, T)
+        mc_g = m[:, None]
+        F0 = jnp.maximum(W - 1 - state.s_fill, 0)[:, None]   # first fold
+        tt_g = jnp.arange(T)[None, :]
+        ready_g = (tt_g < mc_g) & (tt_g >= F0)
+        nready = jnp.maximum(jnp.sum(ready_g, 1, keepdims=True), 1)
+        cq = jnp.sum(jnp.where(ready_g, q, 0.0), 1,
+                     keepdims=True) / nready
+        dq = jnp.where(ready_g, q - cq, 0.0)
+        ps1 = _prefix(dq)                                    # (Q, T+1)
+        ps2 = _prefix(dq * dq)
 
     a = jnp.zeros((Q,), jnp.int32)     # current segment start, global col
     out_cols = [] if mode == "full" else None
@@ -133,103 +139,106 @@ def monitor_fleet_rounds(cfg: MonitorConfig, state, comp, m, *,
 
     for c0 in range(0, T, sub_t):
         L = min(sub_t, T - c0)
-        m_l = jnp.clip(m - c0, 0, L)[:, None]
         n_detect = 1 + (L - 1) // gap    # 1 for any sub_t <= gap
 
         for e in range(n_detect):
-            A = jnp.maximum(a[:, None], F0)
-            (tt, k, have, cnt, qbar, tl, stl, ltl, sig_in, e0) = \
-                segment_planes(c0, L, A, count, mean)
-            resp_in = slide_max_valid(jnp.abs(ltl), CW)[:, 1:]
-            tol = jnp.asarray(P.conv_tol, f32)
-            if P.rel_tol:
-                tol = tol * jnp.maximum(jnp.abs(qbar), 1e-12)
-            convp = (have & (tt < mc_g) & (cnt >= float(gap))
-                     & jnp.isfinite(resp_in) & (resp_in < tol))
-            exists = jnp.any(convp, 1)
-            j1 = jnp.argmax(convp, 1) + c0                   # global col
-            t1 = jnp.where(exists, j1, T)
-            qlast = _take(qbar, (t1 - c0)[:, None])[:, 0]
+            with jax.named_scope("monitor.detect"):
+                A = jnp.maximum(a[:, None], F0)
+                (tt, k, have, cnt, qbar, tl, stl, ltl, sig_in, e0) = \
+                    segment_planes(c0, L, A, count, mean)
+                resp_in = slide_max_valid(jnp.abs(ltl), CW)[:, 1:]
+                tol = jnp.asarray(P.conv_tol, f32)
+                if P.rel_tol:
+                    tol = tol * jnp.maximum(jnp.abs(qbar), 1e-12)
+                convp = (have & (tt < mc_g) & (cnt >= float(gap))
+                         & jnp.isfinite(resp_in) & (resp_in < tol))
+                exists = jnp.any(convp, 1)
+                j1 = jnp.argmax(convp, 1) + c0                   # global col
+                t1 = jnp.where(exists, j1, T)
+                qlast = _take(qbar, (t1 - c0)[:, None])[:, 0]
 
-            if mode == "full":
-                tl_loc = tt - c0
-                span = (tt >= jnp.maximum(a[:, None] - c0, 0) + c0) \
-                    & (tt <= jnp.minimum(t1, c0 + L - 1)[:, None])
-                at1 = (tt == t1[:, None]) & exists[:, None]
-                sig_step = jnp.where(have, sig_in, e0[:, None])
-                if e == 0:
-                    oq = jnp.where(span, qbar, 0.0)
-                    osg = jnp.where(span, sig_step, 0.0)
-                    ocv = at1 & span
-                    oes = jnp.where(span, jnp.where(
-                        at1, qlast[:, None], last[:, None]), 0.0)
-                    oep = jnp.where(span, epoch[:, None]
-                                    + at1.astype(jnp.int32), 0)
-                else:
-                    oq = jnp.where(span, qbar, oq)
-                    osg = jnp.where(span, sig_step, osg)
-                    ocv = ocv | (at1 & span)
-                    oes = jnp.where(span, jnp.where(
-                        at1, qlast[:, None], last[:, None]), oes)
-                    oep = jnp.where(span, epoch[:, None]
-                                    + at1.astype(jnp.int32), oep)
+                if mode == "full":
+                    tl_loc = tt - c0
+                    span = (tt >= jnp.maximum(a[:, None] - c0, 0) + c0) \
+                        & (tt <= jnp.minimum(t1, c0 + L - 1)[:, None])
+                    at1 = (tt == t1[:, None]) & exists[:, None]
+                    sig_step = jnp.where(have, sig_in, e0[:, None])
+                    if e == 0:
+                        oq = jnp.where(span, qbar, 0.0)
+                        osg = jnp.where(span, sig_step, 0.0)
+                        ocv = at1 & span
+                        oes = jnp.where(span, jnp.where(
+                            at1, qlast[:, None], last[:, None]), 0.0)
+                        oep = jnp.where(span, epoch[:, None]
+                                        + at1.astype(jnp.int32), 0)
+                    else:
+                        oq = jnp.where(span, qbar, oq)
+                        osg = jnp.where(span, sig_step, osg)
+                        ocv = ocv | (at1 & span)
+                        oes = jnp.where(span, jnp.where(
+                            at1, qlast[:, None], last[:, None]), oes)
+                        oep = jnp.where(span, epoch[:, None]
+                                        + at1.astype(jnp.int32), oep)
 
-            zf = jnp.zeros_like(count)
-            a = jnp.where(exists, (t1 + 1).astype(jnp.int32), a)
-            count = jnp.where(exists, zf, count)
-            mean = jnp.where(exists, zf, mean)
-            m2 = jnp.where(exists, zf, m2)
-            epoch = epoch + exists.astype(jnp.int32)
-            last = jnp.where(exists, qlast, last)
+                zf = jnp.zeros_like(count)
+                a = jnp.where(exists, (t1 + 1).astype(jnp.int32), a)
+                count = jnp.where(exists, zf, count)
+                mean = jnp.where(exists, zf, mean)
+                m2 = jnp.where(exists, zf, m2)
+                epoch = epoch + exists.astype(jnp.int32)
+                last = jnp.where(exists, qlast, last)
 
         # ---- carry evaluation: no detection (the gap bound rules out a
         # further convergence in this tile); rebuilds the post-reset tail
         # and harvests the chronological histories ----
-        A = jnp.maximum(a[:, None], F0)
-        (tt, k, have, cnt, qbar, tl, stl, ltl, sig_in, e0) = \
-            segment_planes(c0, L, A, count, mean)
-        if mode == "full":
-            span = tt >= a[:, None]
-            sig_step = jnp.where(have, sig_in, e0[:, None])
-            oq = jnp.where(span, qbar, oq)
-            osg = jnp.where(span, sig_step, osg)
-            oes = jnp.where(span, last[:, None], oes)
-            oep = jnp.where(span, epoch[:, None], oep)
-            out_cols.append((jnp.where(ready_g[:, c0:c0 + L],
-                                       q[:, c0:c0 + L], 0.0),
-                             oq, osg, ocv, oes, oep))
+        with jax.named_scope("monitor.carry"):
+            A = jnp.maximum(a[:, None], F0)
+            (tt, k, have, cnt, qbar, tl, stl, ltl, sig_in, e0) = \
+                segment_planes(c0, L, A, count, mean)
+            if mode == "full":
+                span = tt >= a[:, None]
+                sig_step = jnp.where(have, sig_in, e0[:, None])
+                oq = jnp.where(span, qbar, oq)
+                osg = jnp.where(span, sig_step, osg)
+                oes = jnp.where(span, last[:, None], oes)
+                oep = jnp.where(span, epoch[:, None], oep)
+                out_cols.append((jnp.where(ready_g[:, c0:c0 + L],
+                                           q[:, c0:c0 + L], 0.0),
+                                 oq, osg, ocv, oes, oep))
 
-        # Welford carry: absorb this tile's folds of the live segment
-        # [A, absorb_end) into (count, mean, m2) — closed form + Chan
-        absorb = jnp.minimum(mc_g, c0 + L)                   # (Q, 1)
-        kend = jnp.clip(absorb - A, 0, T).astype(f32)
-        havek = kend[:, 0] > 0
-        countF = count + kend[:, 0]
-        S1e = _take(ps1, absorb) - _take(ps1, A)
-        S2e = _take(ps2, absorb) - _take(ps2, A)
-        ke = jnp.maximum(kend, 1.0)
-        mbe = S1e / ke + cq
-        m2be = jnp.maximum(S2e - S1e * S1e / ke, 0.0)
-        de = mbe - mean[:, None]
-        meanF = jnp.where(
-            havek,
-            (mean[:, None] + (S1e + kend * (cq - mean[:, None]))
-             / jnp.maximum(count[:, None] + kend, 1.0))[:, 0], mean)
-        m2F = jnp.where(
-            havek, (m2[:, None] + m2be + de * de * count[:, None] * kend
-                    / jnp.maximum(count[:, None] + kend, 1.0))[:, 0], m2)
-        count, mean, m2 = countF, meanF, m2F
-        # the absorbed folds must not be re-counted by the next tile
-        a = jnp.maximum(a, absorb[:, 0].astype(jnp.int32))
+            # Welford carry: absorb this tile's folds of the live segment
+            # [A, absorb_end) into (count, mean, m2) — closed form + Chan
+            absorb = jnp.minimum(mc_g, c0 + L)                   # (Q, 1)
+            kend = jnp.clip(absorb - A, 0, T).astype(f32)
+            havek = kend[:, 0] > 0
+            countF = count + kend[:, 0]
+            S1e = _take(ps1, absorb) - _take(ps1, A)
+            S2e = _take(ps2, absorb) - _take(ps2, A)
+            ke = jnp.maximum(kend, 1.0)
+            mbe = S1e / ke + cq
+            m2be = jnp.maximum(S2e - S1e * S1e / ke, 0.0)
+            de = mbe - mean[:, None]
+            meanF = jnp.where(
+                havek,
+                (mean[:, None] + (S1e + kend * (cq - mean[:, None]))
+                 / jnp.maximum(count[:, None] + kend, 1.0))[:, 0], mean)
+            m2F = jnp.where(
+                havek, (m2[:, None] + m2be + de * de * count[:, None] * kend
+                        / jnp.maximum(count[:, None] + kend, 1.0))[:, 0], m2)
+            count, mean, m2 = countF, meanF, m2F
+            # the absorbed folds must not be re-counted by the next tile
+            a = jnp.maximum(a, absorb[:, 0].astype(jnp.int32))
 
-        qhist = _take(tl, m_l + jnp.arange(CW)[None, :])
-        shist = _take(stl, m_l + jnp.arange(2)[None, :])
-        rhist = _take(ltl, m_l + jnp.arange(CW)[None, :])
+            m_l = jnp.clip(m - c0, 0, L)[:, None]
+            qhist = _take(tl, m_l + jnp.arange(CW)[None, :])
+            shist = _take(stl, m_l + jnp.arange(2)[None, :])
+            rhist = _take(ltl, m_l + jnp.arange(CW)[None, :])
 
     # ---- dispatch-level carries ----
-    ext = jnp.concatenate([state.win, comp], axis=1)
-    win = _take(ext, m[:, None] + jnp.arange(W)[None, :])
-    s_fill = jnp.minimum(state.s_fill + m, W)
+    with jax.named_scope("monitor.carry"):
+        ext = jnp.concatenate([state.win, comp], axis=1)
+        win = _take(ext, m[:, None] + jnp.arange(W)[None, :])
+        s_fill = jnp.minimum(state.s_fill + m, W)
 
     carry = (s_fill, count, mean, m2, qhist, shist, rhist, epoch, last,
              win)

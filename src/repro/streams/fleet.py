@@ -87,6 +87,7 @@ import threading
 import time
 from typing import Callable, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -366,8 +367,11 @@ class FleetMonitorService:
             # (all three are slice views for co-allocated fleets).  The
             # arena lock bounds the copy-and-zero window against
             # structural growth; cell increments stay lock-free (the
-            # paper's tolerated single-period race).
-            with arena.lock:
+            # paper's tolerated single-period race).  The span names the
+            # dispatch this tick's column goes into.
+            with (jax.profiler.TraceAnnotation(
+                    "repro.fleet.collect", dispatch=self.dispatches + 1),
+                  arena.lock):
                 if arena.layout_version != self._layout_version:
                     self._rebind_slots_locked()   # slots moved (defrag)
                 idx = self._slots
@@ -578,29 +582,38 @@ class FleetMonitorService:
         self._blocked, self._blk_shadow = self._blk_shadow, self._blocked
         self._col = 0
         self._blocked[:] = True
-        emit = self._harvest_locked()   # previous dispatch, now complete
-        self._refresh_slo_locked()      # once per chunk, off the tick
+        # profiler spans, each tagged with this dispatch's number; the
+        # harvest reads the one before it
+        n = self.dispatches + 1
+        span = jax.profiler.TraceAnnotation
+        with span("repro.fleet.harvest", dispatch=n):
+            emit = self._harvest_locked()   # previous dispatch, complete
+        with span("repro.fleet.slo", dispatch=n):
+            self._refresh_slo_locked()      # once per chunk, off the tick
 
         # the estimator consumes (S, cols): one transpose-copy per
         # dispatch, amortized over chunk_t ticks
-        tc = np.ascontiguousarray(tc_rows.T)
-        blocked = np.ascontiguousarray(blk_rows.T)
+        with span("repro.fleet.transpose", dispatch=n):
+            tc = np.ascontiguousarray(tc_rows.T)
+            blocked = np.ascontiguousarray(blk_rows.T)
 
         # per-queue implied service times (period / items) -> fleet cv^2,
         # one fused masked-moment evaluation for the whole tile (rows
         # re-ordered back to per-queue stream order off the tick)
-        q = len(self.queues)
-        head_rows = self._row_of_stream[:q]
-        head_tc, head_blk = tc[head_rows], blocked[head_rows]
-        valid = (head_tc > 0) & ~head_blk
-        self.classifier.update_batch(
-            np.where(valid, self.period_s / np.maximum(head_tc, 1e-30),
-                     0.0), where=valid)
+        with span("repro.fleet.classify", dispatch=n):
+            q = len(self.queues)
+            head_rows = self._row_of_stream[:q]
+            head_tc, head_blk = tc[head_rows], blocked[head_rows]
+            valid = (head_tc > 0) & ~head_blk
+            self.classifier.update_batch(
+                np.where(valid, self.period_s / np.maximum(head_tc, 1e-30),
+                         0.0), where=valid)
 
-        self._state, _ = run_monitor_fleet(
-            self.cfg, tc, blocked, state=self._state,
-            chunk_t=self.chunk_t, impl=self.impl, mode="state",
-            block_q=self.block_q, donate=True)
+        with span("repro.fleet.estimate", dispatch=n):
+            self._state, _ = run_monitor_fleet(
+                self.cfg, tc, blocked, state=self._state,
+                chunk_t=self.chunk_t, impl=self.impl, mode="state",
+                block_q=self.block_q, donate=True)
         self.dispatches += 1
         self._pending = True
         return emit

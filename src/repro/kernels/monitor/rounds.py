@@ -22,11 +22,14 @@ histories the next tile needs.  Histories are the same (Q, cw) buffers
 the sequential form keeps, so all implementations share
 ``FleetMonitorState``.
 
-Everything is shifted-slice ladders and O(Q) gathers — no scatters
-beyond compaction, no cumsum primitives, no per-sample control flow.
-The precompute, each detection and each carry evaluation run under
-``jax.named_scope`` (``monitor.window`` / ``monitor.detect`` /
-``monitor.carry``), so a profiler trace names the phase of every op.
+Everything is shifted-slice ladders and two gather-free per-row
+selections — ``row_shift`` (an offset run of columns, a ladder of
+selects over the offset's bits) and ``row_pick`` (one column, a masked
+row max) — because a TPU v5e runs per-row gathers element by element.
+No scatters beyond compaction, no cumsum primitives, no per-sample
+control flow.  The precompute, each detection and each carry evaluation
+run under ``jax.named_scope`` (``monitor.window`` / ``monitor.detect``
+/ ``monitor.carry``), so a profiler trace names the phase of every op.
 """
 
 from __future__ import annotations
@@ -53,8 +56,28 @@ def _prefix(x):
     return jnp.pad(x, ((0, 0), (1, 0)))
 
 
-def _take(x, idx):
-    return jnp.take_along_axis(x, jnp.clip(idx, 0, x.shape[1] - 1), axis=1)
+def row_shift(x, s, n: int, smax: int):
+    """out[:, j] = x[:, min(s + j, C - 1)] for j < n: a per-row offset
+    s in [0, smax] (Q, 1) into the (Q, C) plane, then n columns — what
+    ``take_along_axis`` with clipped indices returns, built as a binary
+    ladder of selects over the bits of s (no gather)."""
+    bits = [1 << k for k in range(smax.bit_length())]
+    pad = n + sum(bits) - x.shape[1]
+    if pad > 0:            # edge padding keeps the clip at column C-1
+        x = jnp.pad(x, ((0, 0), (0, pad)), mode="edge")
+    for b in reversed(bits):
+        w = n + b - 1
+        x = jnp.where((s & b) != 0, x[:, b:b + w], x[:, :w])
+    return x[:, :n]
+
+
+def row_pick(x, i):
+    """out[:, 0] = x[:, clip(i, 0, C - 1)] for a (Q, 1) column index i:
+    a masked max over the row (no gather).  Exact for every value,
+    signed zeros and infinities included."""
+    col = jnp.arange(x.shape[1])[None, :]
+    hit = col == jnp.clip(i, 0, x.shape[1] - 1)
+    return jnp.max(jnp.where(hit, x, -jnp.inf), axis=1, keepdims=True)
 
 
 def monitor_fleet_rounds(cfg: MonitorConfig, state, comp, m, *,
@@ -103,7 +126,7 @@ def monitor_fleet_rounds(cfg: MonitorConfig, state, comp, m, *,
         have = k > 0
         cnt = count[:, None] + k
         csafe = jnp.maximum(cnt, 1.0)
-        S1 = ps1[:, c0 + 1:c0 + L + 1] - _take(ps1, A)
+        S1 = ps1[:, c0 + 1:c0 + L + 1] - row_pick(ps1, A)
         qbar = jnp.where(
             have, mean[:, None] + (S1 + k * (cq - mean[:, None])) / csafe,
             mean[:, None])
@@ -117,7 +140,7 @@ def monitor_fleet_rounds(cfg: MonitorConfig, state, comp, m, *,
             sig_in = jnp.where(cnt >= CW, stdw[:, 1:], big)
             e0 = jnp.where(count >= CW, stdw[:, 0], big)
         else:
-            S2 = ps2[:, c0 + 1:c0 + L + 1] - _take(ps2, A)
+            S2 = ps2[:, c0 + 1:c0 + L + 1] - row_pick(ps2, A)
             ksafe = jnp.maximum(k, 1.0)
             mb = S1 / ksafe + cq
             m2b = jnp.maximum(S2 - (S1 * S1) / ksafe, 0.0)
@@ -155,7 +178,7 @@ def monitor_fleet_rounds(cfg: MonitorConfig, state, comp, m, *,
                 exists = jnp.any(convp, 1)
                 j1 = jnp.argmax(convp, 1) + c0                   # global col
                 t1 = jnp.where(exists, j1, T)
-                qlast = _take(qbar, (t1 - c0)[:, None])[:, 0]
+                qlast = row_pick(qbar, (t1 - c0)[:, None])[:, 0]
 
                 if mode == "full":
                     tl_loc = tt - c0
@@ -212,8 +235,8 @@ def monitor_fleet_rounds(cfg: MonitorConfig, state, comp, m, *,
             kend = jnp.clip(absorb - A, 0, T).astype(f32)
             havek = kend[:, 0] > 0
             countF = count + kend[:, 0]
-            S1e = _take(ps1, absorb) - _take(ps1, A)
-            S2e = _take(ps2, absorb) - _take(ps2, A)
+            S1e = row_pick(ps1, absorb) - row_pick(ps1, A)
+            S2e = row_pick(ps2, absorb) - row_pick(ps2, A)
             ke = jnp.maximum(kend, 1.0)
             mbe = S1e / ke + cq
             m2be = jnp.maximum(S2e - S1e * S1e / ke, 0.0)
@@ -230,14 +253,14 @@ def monitor_fleet_rounds(cfg: MonitorConfig, state, comp, m, *,
             a = jnp.maximum(a, absorb[:, 0].astype(jnp.int32))
 
             m_l = jnp.clip(m - c0, 0, L)[:, None]
-            qhist = _take(tl, m_l + jnp.arange(CW)[None, :])
-            shist = _take(stl, m_l + jnp.arange(2)[None, :])
-            rhist = _take(ltl, m_l + jnp.arange(CW)[None, :])
+            qhist = row_shift(tl, m_l, CW, L)
+            shist = row_shift(stl, m_l, 2, L)
+            rhist = row_shift(ltl, m_l, CW, L)
 
     # ---- dispatch-level carries ----
     with jax.named_scope("monitor.carry"):
         ext = jnp.concatenate([state.win, comp], axis=1)
-        win = _take(ext, m[:, None] + jnp.arange(W)[None, :])
+        win = row_shift(ext, m[:, None], W, T)
         s_fill = jnp.minimum(state.s_fill + m, W)
 
     carry = (s_fill, count, mean, m2, qhist, shist, rhist, epoch, last,

@@ -206,3 +206,47 @@ def test_fleet_monitor_service_over_instrumented_queues():
     assert emitted and {qi for qi, _ in emitted} <= {0, 1, 2}
     got = svc.rates_items_per_s() * 1e-3      # items/period
     np.testing.assert_allclose(got, rates, rtol=0.05)
+
+
+@pytest.mark.parametrize("C,n,smax", [(48, 16, 32), (34, 2, 32),
+                                      (288, 32, 256), (64, 32, 32),
+                                      (24, 16, 8), (10, 4, 0)])
+def test_rounds_row_selects_match_take_along_axis(C, n, smax):
+    """The rounds form's gather-free selects return exactly what
+    ``take_along_axis`` with clipped indices returns."""
+    from repro.kernels.monitor.rounds import row_pick, row_shift
+    rng = np.random.default_rng(C * 1000 + n)
+    Q = 1000
+    x = rng.normal(size=(Q, C)).astype(np.float32)
+    x[0, :] = -0.0
+    x[1, ::3] = np.inf
+    x[2, 1::3] = -np.inf
+    s = rng.integers(0, smax + 1, (Q, 1)).astype(np.int32)
+    s[:2], s[2:4] = 0, smax
+    idx = np.clip(s + np.arange(n)[None, :], 0, C - 1)
+    got = np.asarray(jax.jit(row_shift, static_argnums=(2, 3))(
+        jnp.asarray(x), jnp.asarray(s), n, smax))
+    want = np.take_along_axis(x, idx, axis=1)
+    assert got.dtype == want.dtype and got.tobytes() == want.tobytes()
+
+    i = rng.integers(-3, C + 3, (Q, 1)).astype(np.int32)
+    i[:4] = [[-1], [C], [0], [C - 1]]
+    got = np.asarray(jax.jit(row_pick)(jnp.asarray(x), jnp.asarray(i)))
+    want = np.take_along_axis(x, np.clip(i, 0, C - 1), axis=1)
+    assert got.dtype == want.dtype and got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("mode", ["state", "full"])
+def test_rounds_lowers_without_gathers(mode):
+    """Per-row gathers run element by element on a TPU; the rounds form
+    selects with shifted slices instead, so its HLO holds no gather."""
+    from repro.kernels.monitor.rounds import monitor_fleet_rounds
+    cfg = MonitorConfig()
+    Q, T = 8, 256
+    state = fleet_monitor_init(cfg, Q)
+    comp = jnp.zeros((Q, T), jnp.float32)
+    m = jnp.full((Q,), T, jnp.int32)
+    step = jax.jit(lambda st, c, mm: monitor_fleet_rounds(
+        cfg, st, c, mm, mode=mode))
+    hlo = step.lower(state, comp, m).as_text()
+    assert "gather" not in hlo.lower()

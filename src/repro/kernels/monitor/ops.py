@@ -65,11 +65,29 @@ def _entry_sigma(cfg: MonitorConfig, state: FleetMonitorState):
                        cw=cfg.conv_window)
 
 
+_DEAD = 1 << 30     # shift-code flag of a blocked (or emptied) slot
+
+
+def _shift_left(a, b: int, fill):
+    """out[:, j] = a[:, j + b], ``fill`` past the right edge: one pad op,
+    which XLA fuses into its consumer, where a slice is copied first."""
+    return jax.lax.pad(a, jnp.asarray(fill, a.dtype),
+                       ((0, 0, 0), (-b, b, 0)))
+
+
 def _compact(tc, blocked):
     """Stream compaction: drop blocked samples, keep time order.
 
-    Returns (comp, m, cnt): compacted samples, per-queue valid counts,
-    and the per-step running valid count used to map results back.
+    Returns (comp, m, cnt): compacted samples (the tail [m, T) zeroed),
+    per-queue valid counts, and the per-step running valid count used to
+    map results back.
+
+    No scatter, sort or gather (a TPU v5e lowers a per-row scatter to a
+    sort of every destination key): a valid sample at column j moves
+    left by d = j + 1 - cnt, the blocked samples before it, in a ladder
+    of shifted selects over d's bits, low bit first.  d never decreases
+    along a row's valid samples, so positions stay strictly increasing
+    after every stage and no two samples meet.
     """
     Q, T = tc.shape
     if blocked is None:
@@ -78,9 +96,17 @@ def _compact(tc, blocked):
     valid = jnp.logical_not(blocked)
     cnt = jnp.cumsum(valid.astype(jnp.int32), axis=1)       # (Q, T)
     m = cnt[:, -1]
-    rows = jnp.arange(Q)[:, None]
-    dest = jnp.where(valid, cnt - 1, T)                     # T = dump slot
-    comp = jnp.zeros((Q, T + 1), tc.dtype).at[rows, dest].set(tc)[:, :T]
+    d = jnp.arange(1, T + 1, dtype=jnp.int32)[None, :] - cnt
+    code = jnp.where(valid, d, _DEAD)     # remaining shift, or dead
+    x = tc
+    for k in range((T - 1).bit_length()):
+        b = 1 << k
+        src = _shift_left(code, b, _DEAD)
+        arrive = (src & (b | _DEAD)) == b       # live, shift has bit b
+        stays = (code & (b | _DEAD)) == 0
+        x = jnp.where(arrive, _shift_left(x, b, 0), x)
+        code = jnp.where(arrive, src, jnp.where(stays, code, _DEAD))
+    comp = jnp.where(code < _DEAD, x, jnp.zeros((), tc.dtype))
     return comp, m, cnt
 
 

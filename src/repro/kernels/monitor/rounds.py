@@ -26,10 +26,11 @@ Everything is shifted-slice ladders and two gather-free per-row
 selections — ``row_shift`` (an offset run of columns, a ladder of
 selects over the offset's bits) and ``row_pick`` (one column, a masked
 row max) — because a TPU v5e runs per-row gathers element by element.
-No scatters beyond compaction, no cumsum primitives, no per-sample
-control flow.  The precompute, each detection and each carry evaluation
-run under ``jax.named_scope`` (``monitor.window`` / ``monitor.detect``
-/ ``monitor.carry``), so a profiler trace names the phase of every op.
+No scatters (the compaction in ``ops.py`` is a select ladder too), no
+cumsum primitives, no per-sample control flow.  The precompute, each
+detection and each carry evaluation run under ``jax.named_scope``
+(``monitor.window`` / ``monitor.detect`` / ``monitor.carry``), so a
+profiler trace names the phase of every op.
 """
 
 from __future__ import annotations

@@ -6,6 +6,8 @@ oracle and the per-sample ``run_monitor`` path on identical streams —
 including convergence-reset epochs and blocked-sample discards.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -250,3 +252,124 @@ def test_rounds_lowers_without_gathers(mode):
         cfg, st, c, mm, mode=mode))
     hlo = step.lower(state, comp, m).as_text()
     assert "gather" not in hlo.lower()
+
+
+def _compact_scatter(tc, blocked):
+    """Stream compaction as a per-row scatter: the form ``_compact``
+    replaced, kept as its reference."""
+    Q, T = tc.shape
+    valid = jnp.logical_not(blocked)
+    cnt = jnp.cumsum(valid.astype(jnp.int32), axis=1)
+    rows = jnp.arange(Q)[:, None]
+    dest = jnp.where(valid, cnt - 1, T)                  # T = dump slot
+    comp = jnp.zeros((Q, T + 1), tc.dtype).at[rows, dest].set(tc)[:, :T]
+    return comp, cnt[:, -1], cnt
+
+
+def _compact_numpy(tc, blocked):
+    """Stable left compaction row by row, zeros after the valid ones."""
+    out = np.zeros_like(tc)
+    for q in range(tc.shape[0]):
+        kept = tc[q][~blocked[q]]
+        out[q, :kept.size] = kept
+    return out
+
+
+def _blocked_mask(kind, Q, T, rng):
+    col = np.broadcast_to(np.arange(T)[None, :], (Q, T))
+    if kind == "none":
+        return np.zeros((Q, T), bool)
+    if kind == "all":
+        return np.ones((Q, T), bool)
+    if kind == "alternating":          # odd rows start blocked
+        return (col + np.arange(Q)[:, None]) % 2 == 0
+    if kind == "first_valid":
+        return col != 0
+    if kind == "last_valid":
+        return col != T - 1
+    return rng.random((Q, T)) < float(kind.removeprefix("random"))
+
+
+@pytest.mark.parametrize("kind", ["none", "all", "alternating",
+                                  "random0.1", "random0.5", "random0.9",
+                                  "first_valid", "last_valid"])
+@pytest.mark.parametrize("T", [1, 7, 32, 200, 256, 257])
+def test_compact_matches_scatter_reference(T, kind):
+    """The shift-and-select ladder returns the scatter form's bytes,
+    signed zeros, infinities and NaN payloads included, and the same
+    valid counts."""
+    from repro.kernels.monitor.ops import _compact
+    Q = 64
+    rng = np.random.default_rng(T * 100 + len(kind))
+    tc = rng.normal(size=(Q, T)).astype(np.float32)
+    tc[:, 0::5] = -0.0
+    tc[:, 1::5] = np.inf
+    tc[:, 2::5] = -np.inf
+    tc[:, 3::7] = np.array([0x7FC12345], np.uint32).view(np.float32)[0]
+    tc[::3, 4::9] = np.array([0xFFC00ABC], np.uint32).view(np.float32)[0]
+    blocked = _blocked_mask(kind, Q, T, rng)
+
+    comp, m, cnt = jax.jit(_compact)(jnp.asarray(tc), jnp.asarray(blocked))
+    r_comp, r_m, r_cnt = jax.jit(_compact_scatter)(jnp.asarray(tc),
+                                                   jnp.asarray(blocked))
+    got = np.asarray(comp)
+    assert got.dtype == np.float32 and got.shape == (Q, T)
+    assert got.tobytes() == np.asarray(r_comp).tobytes()
+    assert got.tobytes() == _compact_numpy(tc, blocked).tobytes()
+    np.testing.assert_array_equal(np.asarray(m), np.asarray(r_m))
+    np.testing.assert_array_equal(np.asarray(m), (~blocked).sum(axis=1))
+    np.testing.assert_array_equal(np.asarray(cnt), np.asarray(r_cnt))
+
+
+@pytest.mark.parametrize("mode", ["state", "full"])
+@pytest.mark.parametrize("chunk_t", [32, 200, 256])
+@pytest.mark.parametrize("cfg", [MonitorConfig(),
+                                 MonitorConfig(sigma_mode="stderr"),
+                                 MonitorConfig.paper_faithful()])
+def test_fleet_bit_identical_to_scatter_compaction(cfg, chunk_t, mode,
+                                                   monkeypatch):
+    """``run_monitor_fleet`` over the ladder compaction ends in the same
+    state bytes, and in full mode the same output planes, as over the
+    scatter form it replaced."""
+    from repro.core import monitor
+    from repro.kernels.monitor import ops
+    tc, blocked = _noisy_streams(Q=6, T=700, seed=chunk_t, p_block=0.3)
+    blocked[4] = True                      # one queue never valid
+    blocked[5, :350] = False               # one long valid run
+
+    def run():   # the jitted steps are cached: trace afresh each time
+        monitor._fleet_dispatch.cache_clear()
+        return run_monitor_fleet(cfg, tc, blocked, chunk_t=chunk_t,
+                                 mode=mode, block_q=8)
+
+    try:
+        st, out = run()
+        monkeypatch.setattr(ops, "_compact", _compact_scatter)
+        r_st, r_out = run()
+    finally:   # no step traced with the patch may serve another test
+        monitor._fleet_dispatch.cache_clear()
+    assert float(np.asarray(st.count).sum()) > 0     # q's were folded
+    for a, b in zip(st, r_st):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert (out is None) == (mode == "state")
+    if out is not None:
+        for a, b in zip(out, r_out):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+@pytest.mark.parametrize("mode", ["state", "full"])
+def test_compact_lowers_without_scatter_or_sort(mode):
+    """A TPU v5e lowers a per-row scatter to a sort of every destination
+    key; the fleet step compacts with shifted selects instead, so with a
+    blocked plane its HLO holds no scatter and no sort (and, in state
+    mode, no gather either)."""
+    cfg = MonitorConfig()
+    Q, T = 8, 256
+    state = fleet_monitor_init(cfg, Q)
+    tc = jnp.zeros((Q, T), jnp.float32)
+    blocked = jnp.zeros((Q, T), jnp.bool_)
+    hlo = fleet_monitor_scan.lower(cfg, state, tc, blocked, impl="rounds",
+                                   mode=mode).as_text()
+    banned = ["scatter", "sort"] + (["gather"] if mode == "state" else [])
+    for op in banned:
+        assert not re.search(rf"stablehlo\.{op}\b", hlo), op

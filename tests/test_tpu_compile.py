@@ -8,6 +8,7 @@ because only one process at a time may load the TPU library.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -18,7 +19,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.control.policy import (ControlConfig, _decide_step,
                                   _jit_operands, control_init)
-from repro.core.monitor import MonitorConfig
+from repro.core.monitor import (MonitorConfig, _fleet_dispatch,
+                                fleet_monitor_init)
 from repro.kernels.monitor.kernel import (N_FSTATE, N_ISTATE,
                                           monitor_fleet_pallas)
 
@@ -91,3 +93,22 @@ def test_control_decision_compiles_for_v5e(one_chip):
     compiled = _decide_step(cfg, True).lower(shapes[0],
                                              **shapes[1]).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_fleet_step_compiles_for_v5e_without_sort_scatter_or_gather(
+        one_chip):
+    """The benchmark's fleet step (rounds, state mode, 256-period tiles,
+    2e5 ends) compiles with no sort, scatter or gather: the v5e runs
+    per-row gathers element by element and lowers a per-row scatter to a
+    sort of every destination key."""
+    cfg = MonitorConfig()
+    state = jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda: fleet_monitor_init(cfg, FLEET_Q)))
+    step = _fleet_dispatch(cfg, "rounds", "state", False, 256, True)
+    tc = _spec((FLEET_Q, 256), jnp.float32, one_chip)
+    blocked = _spec((FLEET_Q, 256), jnp.bool_, one_chip)
+    compiled = step.lower(state, tc, blocked).compile()
+    hlo = compiled.as_text()
+    for op in ("sort", "scatter", "gather"):
+        assert not re.search(rf"\b{op}\(", hlo), op
